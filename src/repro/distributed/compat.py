@@ -1,42 +1,22 @@
-"""Aliases for jax APIs that moved between 0.4.x and current releases.
+"""The jax spellings the distributed code goes through.
 
-The distributed code targets the current jax surface (``jax.shard_map``,
-``jax.sharding.AxisType``); environments pinned to jax 0.4.x still carry
-those under their old names/signatures.  Everything version-dependent goes
-through here so call sites stay on the modern spelling.
+The repository supports the installed jax (see ``requirements.txt``);
+these names keep call sites short and give mesh construction one place
+to pick its axis types.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, /, *, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=True, **kwargs):
-        # 0.4.x spells check_vma as check_rep.
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma,
-                             **kwargs)
+shard_map = jax.shard_map
 
 
 def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict.
-
-    0.4.x returns a one-entry list of per-program dicts; current jax
-    returns the dict directly.
-    """
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``Compiled.cost_analysis()`` as a dict (empty when unavailable)."""
+    return compiled.cost_analysis() or {}
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the argument exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))

@@ -68,11 +68,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _lengths_spec(block_q: int, grid_axis: int):
-    """BlockSpec for the per-row (BH, 1) lengths input: every (qi, ki)
-    step of one batch-head row sees the same scalar."""
-    del block_q, grid_axis
-    return pl.BlockSpec((1, 1), lambda b, i, j: (b, 0))
+# The per-row (BH,) KV lengths sit whole in SMEM; grid step (b, ., .)
+# reads its scalar as ``len_ref[b]``.  Per-query statistics (lse, delta)
+# are (BH, 1, Sq) rows: the unit sublane axis keeps their (1, 1, block_q)
+# blocks within the TPU's tiling.
+_LENGTHS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _kv_mask(len_ref, kpos):
+    """Keys at positions below this batch-head row's length are valid."""
+    return kpos.astype(jnp.float32) < len_ref[pl.program_id(0)]
 
 
 def _mha_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -100,7 +105,7 @@ def _mha_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     k = k_ref[0]                                     # (bk, dh)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kpos.astype(jnp.float32) < len_ref[0, 0]
+    mask = _kv_mask(len_ref, kpos)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_ref[...]                              # (bq, 1)
@@ -119,13 +124,14 @@ def _mha_fwd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # Clamp so fully-masked rows store a huge-negative (finite) lse:
         # the backward's exp(s - lse) then stays finite and the mask
         # multiply zeroes it, instead of inf - inf = NaN.
-        lse_ref[0] = (m_ref[...] + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        lse_ref[0] = (m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))).T
 
 
 def mha_fwd_kernel(q, k, v, lengths, *, block_q: int = 128,
                    block_k: int = 128, interpret: bool = False):
     """q (BH, Sq, dh), k/v (BH, Sk, dh) padded to block multiples;
-    ``lengths`` (BH,) float32 true KV lengths.  Returns (o, lse)."""
+    ``lengths`` (BH,) float32 true KV lengths.  Returns (o, lse) with
+    lse (BH, 1, Sq)."""
     BH, Sq, dh = q.shape
     Sk = k.shape[1]
     assert Sq % block_q == 0 and Sk % block_k == 0
@@ -136,18 +142,18 @@ def mha_fwd_kernel(q, k, v, lengths, *, block_q: int = 128,
         kernel,
         grid=(BH, n_q, n_k),
         in_specs=[
-            _lengths_spec(block_q, 2),
+            _LENGTHS_SPEC,
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),    # running max
@@ -155,7 +161,8 @@ def mha_fwd_kernel(q, k, v, lengths, *, block_q: int = 128,
             pltpu.VMEM((block_q, dh), jnp.float32),   # output accumulator
         ],
         interpret=interpret,
-    )(lengths.reshape(BH, 1).astype(jnp.float32), q, k, v)
+        name="mha_fwd",
+    )(lengths.astype(jnp.float32), q, k, v)
 
 
 def _mha_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -172,10 +179,10 @@ def _mha_bwd_dq_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     q, k, v = q_ref[0], k_ref[0], v_ref[0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = (kpos.astype(jnp.float32) < len_ref[0, 0]).astype(jnp.float32)
-    p = jnp.exp(s - lse_ref[0][:, None]) * mask
+    mask = _kv_mask(len_ref, kpos).astype(jnp.float32)
+    p = jnp.exp(s - lse_ref[0].T) * mask
     dp = jnp.dot(do_ref[0], v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None]) * scale
+    ds = p * (dp - delta_ref[0].T) * scale
     acc_ref[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_k - 1)
@@ -199,12 +206,12 @@ def _mha_bwd_dkv_kernel(len_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     kpos = (pl.program_id(1) * k.shape[0]
             + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    mask = (kpos.astype(jnp.float32) < len_ref[0, 0]).astype(jnp.float32)
-    p = jnp.exp(s - lse_ref[0][:, None]) * mask
+    mask = _kv_mask(len_ref, kpos).astype(jnp.float32)
+    p = jnp.exp(s - lse_ref[0].T) * mask
     do = do_ref[0]
     dv_acc[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
     dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[0][:, None]) * scale
+    ds = p * (dp - delta_ref[0].T) * scale
     dk_acc[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_q - 1)
@@ -220,46 +227,48 @@ def mha_bwd_kernels(q, k, v, do, lse, delta, lengths, *,
 
     All sequence axes must already be padded to block multiples; ``do``
     must be zero in padded query rows (the ops wrapper pads with zeros),
-    so padded rows contribute nothing to dk/dv.
+    so padded rows contribute nothing to dk/dv.  ``lse`` and ``delta``
+    are (BH, 1, Sq).
     """
     BH, Sq, dh = q.shape
     Sk = k.shape[1]
     assert Sq % block_q == 0 and Sk % block_k == 0
     n_q, n_k = Sq // block_q, Sk // block_k
     scale = dh ** -0.5
-    lens2 = lengths.reshape(BH, 1).astype(jnp.float32)
+    lens = lengths.astype(jnp.float32)
 
     dq = pl.pallas_call(
         functools.partial(_mha_bwd_dq_kernel, n_k=n_k, block_k=block_k,
                           scale=scale),
         grid=(BH, n_q, n_k),
         in_specs=[
-            _lengths_spec(block_q, 2),
+            _LENGTHS_SPEC,
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),  # q
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),  # k
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, j, 0)),  # v
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),  # do
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),         # lse
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),         # delta
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),   # lse
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),   # delta
         ],
         out_specs=pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
-    )(lens2, q, k, v, do, lse, delta)
+        name="mha_dq",
+    )(lens, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
         functools.partial(_mha_bwd_dkv_kernel, n_q=n_q, block_k=block_k,
                           scale=scale),
         grid=(BH, n_k, n_q),
         in_specs=[
-            _lengths_spec(block_q, 2),
+            _LENGTHS_SPEC,
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, j, 0)),  # q
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0)),  # k
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0)),  # v
             pl.BlockSpec((1, block_q, dh), lambda b, i, j: (b, j, 0)),  # do
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, j)),         # lse
-            pl.BlockSpec((1, block_q), lambda b, i, j: (b, j)),         # delta
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),   # lse
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j)),   # delta
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, dh), lambda b, i, j: (b, i, 0)),
@@ -274,7 +283,8 @@ def mha_bwd_kernels(q, k, v, do, lse, delta, lengths, *,
             pltpu.VMEM((block_k, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(lens2, q, k, v, do, lse, delta)
+        name="mha_dkv",
+    )(lens, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -3,7 +3,7 @@
 Flattens (B, H) -> BH, repeats KV heads to query heads (simple v1 GQA;
 a grouped-DOT kernel that avoids the repeat is a recorded §Perf follow-up),
 pads sequence lengths to block multiples, and calls the Pallas kernel
-(interpret mode on CPU, compiled on TPU)."""
+(compiled on TPU, interpreted on CPU)."""
 from __future__ import annotations
 
 import functools
@@ -12,14 +12,18 @@ import jax
 import jax.numpy as jnp
 
 from ...obs.profiling import named_scope
+from ...runtime import interpret_kernels
 from .kernel import flash_attention_kernel, mha_bwd_kernels, mha_fwd_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
-    """q (B, Sq, H, dh); k/v (B, Sk, KV, dh); returns (B, Sq, H, dh)."""
+                    block_k: int = 128, interpret: bool | None = None):
+    """q (B, Sq, H, dh); k/v (B, Sk, KV, dh); returns (B, Sq, H, dh).
+    ``interpret=None`` compiles on TPU and interprets on CPU."""
+    if interpret is None:
+        interpret = interpret_kernels()
     with named_scope("mrsch.kernel.flash_attention"):
         return _flash_attention_impl(q, k, v, causal=causal, block_q=block_q,
                                      block_k=block_k, interpret=interpret)
@@ -56,20 +60,15 @@ def _flash_attention_impl(q, k, v, *, causal, block_q, block_k, interpret):
 # sets — the hot path of the queue-as-tokens state encoder
 # (repro.nn.queue_encoder).  Mirrors fused_mlp/ops.py: a custom_vjp whose
 # forward and backward both run Pallas kernels, padding handled inside
-# the vjp boundary, interpret-mode fallback off TPU.
+# the vjp boundary, compiled on TPU and interpreted on CPU.
 
-def _pad_seq(x, mult: int):
-    pad = (-x.shape[1]) % mult
+def _pad_seq(x, mult: int, axis: int = 1):
+    pad = (-x.shape[axis]) % mult
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
-    widths[1] = (0, pad)
+    widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-def default_interpret() -> bool:
-    """Interpret-mode unless a real TPU is attached (fused_mlp semantics)."""
-    return jax.default_backend() != "tpu"
 
 
 def _mha_fwd_impl(q, k, v, lengths, block_q, block_k, interpret):
@@ -78,7 +77,7 @@ def _mha_fwd_impl(q, k, v, lengths, block_q, block_k, interpret):
         o, lse = mha_fwd_kernel(
             _pad_seq(q, block_q), _pad_seq(k, block_k), _pad_seq(v, block_k),
             lengths, block_q=block_q, block_k=block_k, interpret=interpret)
-        return o[:, :Sq], lse[:, :Sq]
+        return o[:, :Sq], lse[:, :, :Sq]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
@@ -104,8 +103,8 @@ def _mha_bwd_impl(block_q, block_k, interpret, res, do):
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(axis=-1)
     dq, dk, dv = mha_bwd_kernels(
         _pad_seq(q, block_q), _pad_seq(k, block_k), _pad_seq(v, block_k),
-        _pad_seq(do, block_q), _pad_seq(lse, block_q),
-        _pad_seq(delta, block_q), lengths,
+        _pad_seq(do, block_q), _pad_seq(lse, block_q, axis=2),
+        _pad_seq(delta[:, None], block_q, axis=2), lengths,
         block_q=block_q, block_k=block_k, interpret=interpret)
     # lengths ride through as a float array (custom_vjp nondiff_argnums
     # cannot carry traced arrays) — their cotangent is defined as zero.
@@ -126,10 +125,10 @@ def mha(q, k, v, lengths=None, *, block_q: int = 128, block_k: int = 128,
     fully-masked row outputs exactly 0, matching ``ref.attention_ref``
     with lengths).  ``None`` means every key is valid.  The backward pass
     runs the fused dq/dkv Pallas kernels via ``jax.custom_vjp``;
-    ``interpret=None`` auto-selects interpret mode off TPU.
+    ``interpret=None`` compiles on TPU and interprets on CPU.
     """
     if interpret is None:
-        interpret = default_interpret()
+        interpret = interpret_kernels()
     BH, _, _ = q.shape
     Sk = k.shape[1]
     if lengths is None:

@@ -88,7 +88,8 @@ def fused_mlp_layer(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
                     block_k: int = 512, interpret: bool = False
                     ) -> jnp.ndarray:
     """x (M,K) @ w (K,N) + b (N,), fused activation.  Shapes are padded to
-    block multiples by the ``ops`` wrapper."""
+    block multiples by the ``ops`` wrapper.  The bias rides as a (1, N)
+    row: Mosaic refuses a 1-D block whose tiling differs from XLA's."""
     _check_activation(activation)
     M, K = x.shape
     _, N = w.shape
@@ -104,13 +105,14 @@ def fused_mlp_layer(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
-            pl.BlockSpec((block_n,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, block_n), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, w, b)
+        name="fused_mlp_fwd",
+    )(x, w, b.reshape(1, N))
 
 
 # ------------------------------------------------------------------ backward
@@ -159,6 +161,7 @@ def fused_mlp_dgrad_layer(g: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((M, K), g.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_k), jnp.float32)],
         interpret=interpret,
+        name="fused_mlp_dgrad",
     )(g, y, w)
 
 
@@ -207,4 +210,5 @@ def fused_mlp_wgrad_layer(x: jnp.ndarray, g: jnp.ndarray, y: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((K, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_k, block_n), jnp.float32)],
         interpret=interpret,
+        name="fused_mlp_wgrad",
     )(x, g, y)

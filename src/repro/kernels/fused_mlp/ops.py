@@ -1,7 +1,7 @@
 """Differentiable public wrapper for the fused-MLP Pallas kernels.
 
 ``fused_mlp`` pads (M, N, K) to block multiples, runs the Pallas kernel
-(interpret mode off-TPU, compiled on TPU), slices the result back, and
+(compiled on TPU, interpreted on CPU), slices the result back, and
 carries a ``jax.custom_vjp`` whose backward pass runs the fused
 dgrad/wgrad kernels — so both DFP inference *and* the ``lax.scan``
 training bursts stay inside the kernel layer.
@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ...obs.profiling import named_scope
+from ...runtime import interpret_kernels
 from .kernel import (_activation_grad, fused_mlp_dgrad_layer, fused_mlp_layer,
                      fused_mlp_wgrad_layer)
 
@@ -52,11 +53,6 @@ def autotune_blocks(m: int, k: int, n: int) -> tuple:
     block_n = min(256, max(128, _pow2(n)))
     block_k = min(512, max(128, _pow2(k)))
     return block_m, block_n, block_k
-
-
-def default_interpret() -> bool:
-    """Compiled Pallas on TPU, interpreter everywhere else (CPU CI)."""
-    return jax.default_backend() != "tpu"
 
 
 # --------------------------------------------------------------- custom VJP
@@ -131,7 +127,8 @@ def fused_mlp(x, w, b, *, activation: str = "leaky_relu", slope: float = 0.2,
     """y = act(x @ w + b) with arbitrary (M, K, N); differentiable.
 
     ``block_* = None`` autotunes on the problem shape; ``interpret =
-    None`` compiles on TPU and interprets elsewhere.
+    None`` compiles on TPU and interprets on CPU
+    (``repro.runtime.interpret_kernels``).
     """
     squeeze = x.ndim == 1
     if squeeze:
@@ -146,7 +143,7 @@ def fused_mlp(x, w, b, *, activation: str = "leaky_relu", slope: float = 0.2,
     if block_k is not None:
         bk = block_k
     if interpret is None:
-        interpret = default_interpret()
+        interpret = interpret_kernels()
     y = _fused_mlp_jit(x, w, b, activation, float(slope), bm, bn, bk,
                        bool(interpret))
     return y[0] if squeeze else y

@@ -11,15 +11,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...runtime import interpret_kernels
 from .kernel import ssd_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd(x, dt, dA, B, C, *, chunk: int = 256, interpret: bool = True):
+def ssd(x, dt, dA, B, C, *, chunk: int = 256,
+        interpret: bool | None = None):
     """x (B,S,H,P); dt/dA (B,S,H); B/C (B,S,H,N) -> y (B,S,H,P).
 
     ``dA`` = dt * A (negative); the kernel consumes the in-chunk cumsum.
+    ``interpret=None`` compiles on TPU and interprets on CPU.
     """
+    if interpret is None:
+        interpret = interpret_kernels()
     b, S, H, P = x.shape
     N = B.shape[-1]
     pad = (-S) % chunk

@@ -12,8 +12,7 @@ Two complementary mechanisms:
   dispatch, serve micro-batch, train step), visible on the Python
   timeline of a captured profile.
 
-Both degrade to no-op context managers when the underlying jax API is
-unavailable, so instrumented code never needs to guard.
+Both are thin wrappers over jax's own.
 
 :func:`span` is the tracer-facing counterpart: it measures a wall-clock
 phase and emits a ``prof.span`` event, which `tools/trace_report.py`
@@ -25,31 +24,21 @@ import contextlib
 import time
 from typing import ContextManager
 
+import jax
+
 from .trace import NULL, Tracer
 
 __all__ = ["annotate", "named_scope", "span"]
 
-try:  # pragma: no cover - import guard
-    import jax
-except Exception:  # pragma: no cover
-    jax = None
-
 
 def annotate(name: str) -> ContextManager:
-    """Host-side ``jax.profiler.TraceAnnotation(name)`` (no-op fallback)."""
-    prof = getattr(jax, "profiler", None) if jax is not None else None
-    cls = getattr(prof, "TraceAnnotation", None) if prof is not None else None
-    if cls is None:  # pragma: no cover - jax always has it in CI
-        return contextlib.nullcontext()
-    return cls(name)
+    """Host-side ``jax.profiler.TraceAnnotation(name)``."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 def named_scope(name: str) -> ContextManager:
-    """Trace-time ``jax.named_scope(name)`` (no-op fallback)."""
-    fn = getattr(jax, "named_scope", None) if jax is not None else None
-    if fn is None:  # pragma: no cover
-        return contextlib.nullcontext()
-    return fn(name)
+    """Trace-time ``jax.named_scope(name)``."""
+    return jax.named_scope(name)
 
 
 @contextlib.contextmanager

@@ -74,6 +74,7 @@ from .metrics import MetricsAccumulator
 from .simulator import SimConfig, SimResult
 
 INF = jnp.float32(jnp.inf)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class DeviceFaults(NamedTuple):
@@ -386,8 +387,12 @@ def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
                       & (k[:, :, None] <= cum[:, None, :]))        # (N, cap, J)
             assign_f = assign.astype(jnp.float32)
             any_assign = assign.any(axis=2)
-            owner_val = jnp.einsum("nuj,j->nu", assign_f, jidx_f)
-            rel_val = jnp.einsum("nuj,nj->nu", assign_f, est_all)
+            # One-hot gathers: HIGHEST keeps job indices and release
+            # times exact on the TPU's MXU (default f32 passes are bf16).
+            owner_val = jnp.einsum("nuj,j->nu", assign_f, jidx_f,
+                                   precision=HIGHEST)
+            rel_val = jnp.einsum("nuj,nj->nu", assign_f, est_all,
+                                 precision=HIGHEST)
             release = release.at[:, off:off + cap].set(
                 jnp.where(any_assign, rel_val, seg))
             owner = owner.at[:, off:off + cap].set(
@@ -437,7 +442,7 @@ def _meas_goal(layout: DeviceLayout, arrays, st, free, waiting,
     running = st["started"] & ~st["finished"]
     tw = (arrays["walltime"] * waiting
           + jnp.maximum(st["est_end"] - now[:, None], 0.0) * running)
-    acc = jnp.einsum("nj,njr->nr", tw, arrays["demands"])
+    acc = jnp.einsum("nj,njr->nr", tw, arrays["demands"], precision=HIGHEST)
     demand_time = acc / caps_f[None, :]
     total = demand_time.sum(axis=1, keepdims=True)
     goal = jnp.where(total > 0, demand_time / jnp.maximum(total, 1e-30),

@@ -236,6 +236,39 @@ def test_mha_no_lengths_is_dense_attention():
                                rtol=2e-4, atol=2e-4)
 
 
+def test_mha_lse_rows_match_masked_logsumexp():
+    """The forward's (BH, 1, Sq) log-sum-exp rows — one per query, across
+    two query blocks — equal the masked reference logsumexp."""
+    from repro.kernels.flash_attention.ops import _mha_fwd_impl
+    S, dh = 129, 16
+    q, k, v, lens = _mha_case(S, dh, seed=21)
+    _, lse = _mha_fwd_impl(q, k, v, lens, 128, 128, True)
+    assert lse.shape == (q.shape[0], 1, S)
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * dh ** -0.5
+    keep = jnp.arange(S)[None, None, :] < lens[:, None, None]
+    ref = jax.nn.logsumexp(jnp.where(keep, s, -jnp.inf), axis=-1)
+    rows = np.asarray(lens) > 0
+    np.testing.assert_allclose(np.asarray(lse)[rows, 0],
+                               np.asarray(ref)[rows], rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------------------------------ window pack
+# J spans several 256-job chunks, so the waiting count carries across
+# grid steps; N is no multiple of the 8-row env block.
+@pytest.mark.parametrize("n,j,window,density", [
+    (5, 700, 10, 0.4), (3, 1000, 128, 0.2), (9, 600, 10, 0.01)])
+def test_window_pack_multi_chunk_matches_reference(n, j, window, density):
+    from repro.kernels.window_pack.ops import pack_window
+    rng = np.random.default_rng(j)
+    waiting = (rng.uniform(size=(n, j)) < density).astype(np.float32)
+    feats = rng.normal(size=(n, j, 4)).astype(np.float32) * 1e5
+    ref = pack_window(waiting, feats, window=window, use_pallas=False)
+    ker = pack_window(waiting, feats, window=window, use_pallas=True,
+                      interpret=True)
+    for got, want in zip(ker, ref):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -------------------------------------------------------------------- ssd
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [
     (1, 64, 2, 16, 8, 16), (2, 100, 3, 16, 8, 32), (1, 256, 4, 32, 16, 64),

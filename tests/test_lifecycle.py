@@ -5,7 +5,7 @@ a workflow and a fault scenario (both NN backends) and a hypothesis
 property that topological eligibility order is never violated."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import AgentConfig, FCFSPolicy, MRSchAgent
 from repro.sim import (FAILED, FINISHED, DeviceSimulator, DrainEvent,

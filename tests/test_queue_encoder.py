@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import AgentConfig, MRSchAgent, evaluate, train_agent
 from repro.core.dfp import DFPConfig, action_values, init_params, loss_fn

@@ -1,7 +1,7 @@
 """Simulator invariants: allocation, EASY backfill, metrics."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.sim import Cluster, Job, ResourceSpec, SimConfig, Simulator, run_trace
 from repro.core import FCFSPolicy
