@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.window_pack.ops import pack_window
-from ..obs.profiling import annotate
+from ..obs.profiling import annotate, named_scope
 from ..obs.trace import Tracer
 from .cluster import TTF_HORIZON, Cluster, ResourceSpec
 from .job import Job
@@ -175,9 +175,11 @@ class DeviceRollout:
 
     @property
     def results(self) -> List[SimResult]:
-        """Per-env ``SimResult``s in jobset order (built on demand)."""
+        """Per-env ``SimResult``s in jobset order (built on demand, under
+        ``mrsch.device.results`` on a profiler's host timeline)."""
         if self._cache is None:
-            self._cache = self._build()
+            with annotate("mrsch.device.results"):
+                self._cache = self._build()
         return self._cache
 
     def transitions(self):
@@ -285,28 +287,31 @@ def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
     its failure point, exactly like an immediate start."""
     N, J, R = layout.n_envs, layout.n_jobs, layout.n_resources
     now = st["now"]
-    t_res = _earliest_fit(layout, st["release"], free, d_star, now)
-    do_bf = need & jnp.isfinite(t_res)
-    # Shadow: free units at t_res (estimated releases) minus the
-    # reservation's demand, per resource.
-    shadow_cols = []
-    for r, (off, cap) in enumerate(layout.segments):
-        free_at = jnp.sum(st["release"][:, off:off + cap] <= t_res[:, None],
-                          axis=1).astype(jnp.float32)
-        shadow_cols.append(free_at - d_star[:, r])
-    shadow = jnp.stack(shadow_cols, axis=1)
+    with named_scope("mrsch.scan.backfill_fit"):
+        t_res = _earliest_fit(layout, st["release"], free, d_star, now)
+        do_bf = need & jnp.isfinite(t_res)
+        # Shadow: free units at t_res (estimated releases) minus the
+        # reservation's demand, per resource.
+        shadow_cols = []
+        for r, (off, cap) in enumerate(layout.segments):
+            free_at = jnp.sum(
+                st["release"][:, off:off + cap] <= t_res[:, None],
+                axis=1).astype(jnp.float32)
+            shadow_cols.append(free_at - d_star[:, r])
+        shadow = jnp.stack(shadow_cols, axis=1)
 
-    ends_before_all = arrays["walltime"] + now[:, None] <= t_res[:, None]
+        ends_before_all = arrays["walltime"] + now[:, None] <= t_res[:, None]
 
-    # The queue walk's carry only changes when a candidate actually
-    # starts, and availability only ever decreases — so walking the
-    # queue in order debiting as we go is equivalent to repeatedly
-    # starting the FIRST still-fitting candidate.  That turns an O(J)
-    # sequential scan into a while_loop with one iteration per started
-    # job (almost always 0-2), each a vectorized pass over the queue.
-    jidx = jnp.arange(J)
-    cand = (do_bf[:, None] & (waiting > 0.5)
-            & (jidx[None, :] != j_star[:, None]))          # (N, J)
+        # The queue walk's carry only changes when a candidate actually
+        # starts, and availability only ever decreases — so walking the
+        # queue in order debiting as we go is equivalent to repeatedly
+        # starting the FIRST still-fitting candidate.  That turns an O(J)
+        # sequential scan into a while_loop with one iteration per
+        # started job (almost always 0-2), each a vectorized pass over
+        # the queue.
+        jidx = jnp.arange(J)
+        cand = (do_bf[:, None] & (waiting > 0.5)
+                & (jidx[None, :] != j_star[:, None]))          # (N, J)
 
     def fitting(free_c, shadow_c, go):
         # Per-resource (N, J) compares: XLA:CPU runs these an order of
@@ -363,9 +368,10 @@ def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
         go = go | accept
         return (free_c, shadow_c, go, fitting(free_c, shadow_c, go))
 
-    go0 = jnp.zeros((N, J), bool)
-    _, _, bf_start, _ = jax.lax.while_loop(
-        cond, body, (free, shadow, go0, fitting(free, shadow, go0)))
+    with named_scope("mrsch.scan.backfill_walk"):
+        go0 = jnp.zeros((N, J), bool)
+        _, _, bf_start, _ = jax.lax.while_loop(
+            cond, body, (free, shadow, go0, fitting(free, shadow, go0)))
 
     # Unit assignment, one batched pass per resource: job j takes the
     # free units whose free-rank falls in its cumulative-demand span —
@@ -417,7 +423,8 @@ def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
                                         st["cur_fail"])
         return out
 
-    return jax.lax.cond(bf_start.any(), assign_units, lambda st: st, st)
+    with named_scope("mrsch.scan.backfill_assign"):
+        return jax.lax.cond(bf_start.any(), assign_units, lambda st: st, st)
 
 
 def _meas_goal(layout: DeviceLayout, arrays, st, free, waiting,
@@ -589,87 +596,102 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
         [arrays["static_feats"], arrays["submit_feat"][..., None]],
         axis=-1)
 
+    # Each phase of a round opens a flat ``mrsch.scan.*`` scope (see
+    # docs/observability.md): every op of the body carries at most one,
+    # and the scan and the live/idle cond stay outside them all.
     def decide(s):
         now = s["now"]
-        waiting = device_queued(s["ready"], now, s["started"], s["finished"],
-                                s["failed"]).astype(jnp.float32)
-        n_waiting = waiting.sum(axis=1)
-        need = s["in_pass"] & (n_waiting > 0) & ~s["done"]
-        free = _segment_free(layout, s["release"])
-        # The attention module observes the first queue_cap waiting jobs;
-        # one pack covers both the Q-token state and (its leading W
-        # slots) the action window.
-        attention = layout.state_module == "attention"
-        K = layout.queue_cap if attention else W
-        pk_feats, pk_idx, pk_valid = pack_window(waiting, feats, window=K)
-        win_idx, win_valid = pk_idx[:, :W], pk_valid[:, :W]
-        if not layout.requires_obs:
-            obs = win_valid.astype(jnp.float32)
-        else:
-            meas, goal = _meas_goal(layout, arrays, s, free, waiting,
-                                    has_drains)
-            if attention:
-                obs = _build_obs_attention(layout, arrays, s, waiting,
-                                           pk_feats, pk_valid, meas, goal)
+        with named_scope("mrsch.scan.pack"):
+            waiting = device_queued(s["ready"], now, s["started"],
+                                    s["finished"],
+                                    s["failed"]).astype(jnp.float32)
+            n_waiting = waiting.sum(axis=1)
+            need = s["in_pass"] & (n_waiting > 0) & ~s["done"]
+            free = _segment_free(layout, s["release"])
+            # The attention module observes the first queue_cap waiting
+            # jobs; one pack covers both the Q-token state and (its
+            # leading W slots) the action window.
+            attention = layout.state_module == "attention"
+            K = layout.queue_cap if attention else W
+            pk_feats, pk_idx, pk_valid = pack_window(waiting, feats, window=K)
+            win_idx, win_valid = pk_idx[:, :W], pk_valid[:, :W]
+        with named_scope("mrsch.scan.obs"):
+            if not layout.requires_obs:
+                obs = win_valid.astype(jnp.float32)
             else:
-                obs = _build_obs(layout, arrays, s, pk_feats, pk_valid,
-                                 meas, goal)
-        # Jobs a host Simulator would drop from the observable window this
-        # decision (ScheduleMetrics.truncated_jobs; the attention module
-        # still reports window truncation so the A/B comparison reads the
-        # same pressure signal for both modules).
-        overflow = jnp.maximum(n_waiting - float(W), 0.0).astype(jnp.int32)
-        s = {**s, "truncated": s["truncated"] + need * overflow}
-        scores = score_fn(policy_state, obs)[:, :W]
-        masked = jnp.where(win_valid, scores, -INF)
-        a = jnp.argmax(masked, axis=1).astype(jnp.int32)
-        if explore:
-            k_next, k1, k2 = jax.random.split(s["key"], 3)
-            n_valid = win_valid.sum(axis=1).astype(jnp.float32)
-            a_rand = jnp.floor(jax.random.uniform(k2, (N,))
-                               * jnp.maximum(n_valid, 1.0)).astype(jnp.int32)
-            roll = jax.random.uniform(k1, (N,)) < eps
-            a = jnp.where(roll, a_rand, a)
-            s = {**s, "key": k_next}
-        j_star = jnp.take_along_axis(win_idx, a[:, None], axis=1)[:, 0]
-        d_star = jnp.take_along_axis(
-            arrays["demands"], j_star[:, None, None], axis=1)[:, 0]   # (N, R)
-        fits = jnp.all(d_star <= free, axis=1)
-        start_env = need & fits
-        reserve_env = need & ~fits
-        # --- immediate start (scheduling pass continues).  The attempt's
-        # actual duration is its failure point when the attempt is doomed
-        # (lifecycle.device_attempt); the unit-release ESTIMATE still uses
-        # the walltime, exactly like the host.
-        if A:
-            dur_all, will_fail_all = device_attempt(
-                arrays["fail_times"], s["requeues"], arrays["runtime"])
-        else:
-            dur_all, will_fail_all = arrays["runtime"], None
-        wall_star = jnp.take_along_axis(arrays["walltime"], j_star[:, None],
-                                        axis=1)[:, 0]
-        run_star = jnp.take_along_axis(dur_all, j_star[:, None],
-                                       axis=1)[:, 0]
-        est = now + wall_star
-        release, owner = _alloc_first_free(
-            layout, s["release"], s["owner"], start_env, j_star, d_star, est)
-        sel = (jidx[None, :] == j_star[:, None]) & start_env[:, None]
-        s = {**s, "release": release, "owner": owner,
-             "started": s["started"] | sel,
-             "start": jnp.where(sel, now[:, None], s["start"]),
-             "end": jnp.where(sel, (now + run_star)[:, None], s["end"]),
-             "est_end": jnp.where(sel, est[:, None], s["est_end"]),
-             "first_start_j": jnp.where(sel & (s["first_start_j"] < 0),
-                                        now[:, None], s["first_start_j"]),
-             "decisions": s["decisions"] + need,
-             "first_start": jnp.where(start_env,
-                                      jnp.minimum(s["first_start"], now),
-                                      s["first_start"])}
-        if A:
-            wf_star = jnp.take_along_axis(will_fail_all, j_star[:, None],
-                                          axis=1)[:, 0]
-            s = {**s, "cur_fail": jnp.where(sel, wf_star[:, None],
-                                            s["cur_fail"])}
+                meas, goal = _meas_goal(layout, arrays, s, free, waiting,
+                                        has_drains)
+                if attention:
+                    obs = _build_obs_attention(layout, arrays, s, waiting,
+                                               pk_feats, pk_valid, meas,
+                                               goal)
+                else:
+                    obs = _build_obs(layout, arrays, s, pk_feats, pk_valid,
+                                     meas, goal)
+        with named_scope("mrsch.scan.pack"):
+            # Jobs a host Simulator would drop from the observable window
+            # this decision (ScheduleMetrics.truncated_jobs; the attention
+            # module still reports window truncation so the A/B comparison
+            # reads the same pressure signal for both modules).
+            overflow = jnp.maximum(n_waiting - float(W),
+                                   0.0).astype(jnp.int32)
+            s = {**s, "truncated": s["truncated"] + need * overflow}
+        with named_scope("mrsch.scan.score"):
+            scores = score_fn(policy_state, obs)[:, :W]
+            masked = jnp.where(win_valid, scores, -INF)
+            a = jnp.argmax(masked, axis=1).astype(jnp.int32)
+            if explore:
+                k_next, k1, k2 = jax.random.split(s["key"], 3)
+                n_valid = win_valid.sum(axis=1).astype(jnp.float32)
+                a_rand = jnp.floor(
+                    jax.random.uniform(k2, (N,))
+                    * jnp.maximum(n_valid, 1.0)).astype(jnp.int32)
+                roll = jax.random.uniform(k1, (N,)) < eps
+                a = jnp.where(roll, a_rand, a)
+                s = {**s, "key": k_next}
+        with named_scope("mrsch.scan.start"):
+            j_star = jnp.take_along_axis(win_idx, a[:, None], axis=1)[:, 0]
+            d_star = jnp.take_along_axis(
+                arrays["demands"], j_star[:, None, None],
+                axis=1)[:, 0]                                     # (N, R)
+            fits = jnp.all(d_star <= free, axis=1)
+            start_env = need & fits
+            reserve_env = need & ~fits
+            # --- immediate start (scheduling pass continues).  The
+            # attempt's actual duration is its failure point when the
+            # attempt is doomed (lifecycle.device_attempt); the
+            # unit-release ESTIMATE still uses the walltime, exactly like
+            # the host.
+            if A:
+                dur_all, will_fail_all = device_attempt(
+                    arrays["fail_times"], s["requeues"], arrays["runtime"])
+            else:
+                dur_all, will_fail_all = arrays["runtime"], None
+            wall_star = jnp.take_along_axis(arrays["walltime"],
+                                            j_star[:, None], axis=1)[:, 0]
+            run_star = jnp.take_along_axis(dur_all, j_star[:, None],
+                                           axis=1)[:, 0]
+            est = now + wall_star
+            release, owner = _alloc_first_free(
+                layout, s["release"], s["owner"], start_env, j_star, d_star,
+                est)
+            sel = (jidx[None, :] == j_star[:, None]) & start_env[:, None]
+            s = {**s, "release": release, "owner": owner,
+                 "started": s["started"] | sel,
+                 "start": jnp.where(sel, now[:, None], s["start"]),
+                 "end": jnp.where(sel, (now + run_star)[:, None], s["end"]),
+                 "est_end": jnp.where(sel, est[:, None], s["est_end"]),
+                 "first_start_j": jnp.where(sel & (s["first_start_j"] < 0),
+                                            now[:, None], s["first_start_j"]),
+                 "decisions": s["decisions"] + need,
+                 "first_start": jnp.where(start_env,
+                                          jnp.minimum(s["first_start"], now),
+                                          s["first_start"])}
+            if A:
+                wf_star = jnp.take_along_axis(will_fail_all, j_star[:, None],
+                                              axis=1)[:, 0]
+                s = {**s, "cur_fail": jnp.where(sel, wf_star[:, None],
+                                                s["cur_fail"])}
         # --- reservation + EASY backfill (scheduling pass ends).  The
         # call is cheap when no env reserved (no fitting candidates ->
         # zero queue-walk iterations, unit assignment conditioned out),
@@ -678,8 +700,10 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
             s = _easy_backfill(layout, arrays, s, free, reserve_env,
                                waiting, j_star, d_star, dur_all,
                                will_fail_all)
-        s = {**s, "in_pass": s["in_pass"] & ~reserve_env}
-        a_out = jnp.where(need, a, -1)
+        with named_scope("mrsch.scan.start"):
+            # Envs that reserved end their scheduling pass.
+            s = {**s, "in_pass": s["in_pass"] & ~reserve_env}
+            a_out = jnp.where(need, a, -1)
         obs_out = obs if collect else jnp.zeros((N, 0), jnp.float32)
         dec = ((j_star, fits, n_waiting.astype(jnp.int32)) if trace else ())
         return s, a_out, need, obs_out, dec
@@ -690,14 +714,15 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
         # / drain / restore) from decide-phase starts, so a job killed
         # and restarted at the SAME timestamp decodes as both events.
         s_pre = s
-        s = _advance_events(layout, arrays, faults, s)
+        with named_scope("mrsch.scan.advance"):
+            s = _advance_events(layout, arrays, faults, s)
+            # Single-pop advancement can leave an env in_pass with an
+            # empty queue (completion-only timestamp) — only envs with
+            # waiting jobs actually need a decision this round.
+            qa = device_queued(s["ready"], s["now"], s["started"],
+                               s["finished"], s["failed"]).any(axis=1)
+            any_need = jnp.any(s["in_pass"] & ~s["done"] & qa)
         s_adv = s
-        # Single-pop advancement can leave an env in_pass with an empty
-        # queue (completion-only timestamp) — only envs with waiting
-        # jobs actually need a decision this round.
-        qa = device_queued(s["ready"], s["now"], s["started"], s["finished"],
-                           s["failed"]).any(axis=1)
-        any_need = jnp.any(s["in_pass"] & ~s["done"] & qa)
 
         def live(s):
             return decide(s)
@@ -962,31 +987,38 @@ class DeviceSimulator:
         ``trace=True`` (a separate jit specialization) scans out the
         per-round lifecycle deltas that ``emit_trace`` decodes into the
         ``mrsch.trace/v1`` event stream.
+
+        On a profiler's host timeline ``mrsch.device.rollout`` spans the
+        call: ``mrsch.device.dispatch`` the asynchronous launch, and
+        ``mrsch.device.fetch`` the wait for the device and the copy of
+        its outputs to the host.
         """
         explore = eps is not None
         with annotate("mrsch.device.rollout"):
-            raw = self._fn(explore, collect, trace)(
-                self.arrays, self.faults_arrays, self.policy.init_state(),
-                jnp.float32(0.0 if eps is None else eps),
-                jax.random.PRNGKey(seed))
-        tr = raw.pop("trace", None)
-        out = {k: np.asarray(v) for k, v in raw.items()}
-        if tr is not None:
-            tr = {k: np.asarray(v) for k, v in tr.items()}
-        if not out["done"].all():
-            raise RuntimeError(
-                f"device rollout exhausted its round budget "
-                f"({self.layout.rounds}); raise SimConfig.max_rounds")
-        decided = out["decided"]
-        self.stats = DeviceStats(
-            rounds=int(decided.any(axis=1).sum()),
-            decisions=int(decided.sum()),
-            policy_calls=int(decided.any(axis=1).sum()),
-            max_batch=int(decided.sum(axis=1).max(initial=0)))
-        return DeviceRollout(
-            actions=out["actions"], decided=decided,
-            stats=self.stats, obs=out.get("obs"), trace=tr,
-            _build=lambda: self._results(out))
+            with annotate("mrsch.device.dispatch"):
+                raw = self._fn(explore, collect, trace)(
+                    self.arrays, self.faults_arrays, self.policy.init_state(),
+                    jnp.float32(0.0 if eps is None else eps),
+                    jax.random.PRNGKey(seed))
+            with annotate("mrsch.device.fetch"):
+                tr = raw.pop("trace", None)
+                out = {k: np.asarray(v) for k, v in raw.items()}
+                if tr is not None:
+                    tr = {k: np.asarray(v) for k, v in tr.items()}
+            if not out["done"].all():
+                raise RuntimeError(
+                    f"device rollout exhausted its round budget "
+                    f"({self.layout.rounds}); raise SimConfig.max_rounds")
+            decided = out["decided"]
+            self.stats = DeviceStats(
+                rounds=int(decided.any(axis=1).sum()),
+                decisions=int(decided.sum()),
+                policy_calls=int(decided.any(axis=1).sum()),
+                max_batch=int(decided.sum(axis=1).max(initial=0)))
+            return DeviceRollout(
+                actions=out["actions"], decided=decided,
+                stats=self.stats, obs=out.get("obs"), trace=tr,
+                _build=lambda: self._results(out))
 
     def emit_trace(self, ro: DeviceRollout, tracer: Tracer,
                    env_ids: Optional[Sequence[int]] = None) -> None:

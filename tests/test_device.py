@@ -218,3 +218,75 @@ def test_for_engine_is_the_single_constructor_path():
         SimConfig.for_engine("vector", window=0)
     with pytest.raises(ValueError):
         SimConfig.for_engine("device", max_rounds=0)
+
+
+# ----------------------------------------------------------- profiler names
+SCAN_PHASES = {"advance", "pack", "obs", "score", "start", "backfill_fit",
+               "backfill_walk", "backfill_assign"}
+
+
+@pytest.mark.parametrize("state_module", ["mlp", "attention"])
+def test_scan_phases_name_the_compiled_rollout(state_module):
+    """Every phase of a round carries its ``mrsch.scan.*`` scope in the
+    compiled program's op_names, flat (no op under two phases), with the
+    kernel scopes innermost; the one gather outside the live-round cond
+    (the event pump's ``device_free_units``) lies in ``advance``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    agent = MRSchAgent(RES, AgentConfig(
+        state_hidden=(32, 16), state_out=8, module_hidden=4,
+        backend="pallas", state_module=state_module, queue_cap=16,
+        attn_dim=8, attn_heads=2, attn_layers=1))
+    sim = DeviceSimulator(RES, [synth_jobs(s, n=12) for s in range(2)],
+                          agent, SimConfig.for_engine("device", backfill=True))
+    text = sim._fn(False, False).lower(
+        sim.arrays, sim.faults_arrays, agent.init_state(), jnp.float32(0.0),
+        jax.random.PRNGKey(0)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    scan = re.compile(r"mrsch\.scan\.(\w+)")
+    assert {p for n in names for p in scan.findall(n)} == SCAN_PHASES
+    assert all(len(set(scan.findall(n))) <= 1 for n in names)
+    # The phase opens outside the kernel scope (on the CPU the Pallas
+    # interpreter repeats the outer path inside the kernel's loops, and
+    # its reducer computations carry no path at all).
+    kernels = [n for n in names if "mrsch.kernel." in n and scan.search(n)]
+    assert all(n.index("mrsch.scan.") < n.index("mrsch.kernel.")
+               for n in kernels)
+    placed = {(re.search(r"mrsch\.kernel\.(\w+)", n).group(1),
+               scan.search(n).group(1)) for n in kernels}
+    assert placed == ({("window_pack", "pack"), ("fused_mlp", "score")}
+                      | ({("mha_fwd", "score")}
+                         if state_module == "attention" else set()))
+    pump = [n for n in names
+            if n.endswith("take_along_axis)/gather") and "/cond/" not in n]
+    assert pump and all("/mrsch.scan.advance/" in n for n in pump)
+
+
+def test_rollout_and_results_open_device_spans(monkeypatch):
+    """``rollout()`` opens ``mrsch.device.rollout`` around the dispatch
+    and the fetch; building ``results`` opens ``mrsch.device.results``
+    once, outside it."""
+    import contextlib
+
+    from repro.sim import device
+    opened, stack = [], []
+
+    @contextlib.contextmanager
+    def record(name):
+        stack.append(name)
+        opened.append(tuple(stack))
+        try:
+            yield
+        finally:
+            stack.pop()
+
+    monkeypatch.setattr(device, "annotate", record)
+    ro = DeviceSimulator(RES, [synth_jobs(0, n=12)], FCFSPolicy()).rollout()
+    assert opened == [("mrsch.device.rollout",),
+                      ("mrsch.device.rollout", "mrsch.device.dispatch"),
+                      ("mrsch.device.rollout", "mrsch.device.fetch")]
+    opened.clear()
+    assert ro.results[0].n_unstarted == 0 and ro.results is ro.results
+    assert opened == [("mrsch.device.results",)]
