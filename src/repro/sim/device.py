@@ -146,11 +146,13 @@ class DeviceStats:
     decisions: int = 0
     policy_calls: int = 0            # one in-graph score per active round
     max_batch: int = 0
+    free_passes: int = 0             # (N, U) compare passes freeing units
 
     def as_dict(self) -> dict:
         return {"rounds": self.rounds, "decisions": self.decisions,
                 "policy_calls": self.policy_calls,
-                "max_batch": self.max_batch}
+                "max_batch": self.max_batch,
+                "free_passes": self.free_passes}
 
 
 @dataclass
@@ -203,8 +205,9 @@ def _segment_free(layout: DeviceLayout, release: jnp.ndarray) -> jnp.ndarray:
 def _advance_events(layout: DeviceLayout, arrays, faults: DeviceFaults, st):
     """Batched event step: pop+apply ONE coalesced timestamp per env not
     inside a scheduling pass.  Runs inline in the round body (no
-    ``while_loop`` — its computation boundaries dominate the per-round
-    cost on small problems); an env that pops a decision-free timestamp
+    ``while_loop`` over pops — its computation boundaries dominate the
+    per-round cost on small problems; freeing units loops only over the
+    jobs that end); an env that pops a decision-free timestamp
     simply pops again next round, which the round budget covers.
 
     Events at one timestamp apply in the host engines' kind order:
@@ -587,6 +590,7 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
         "decisions": jnp.zeros(N, jnp.int32),
         "truncated": jnp.zeros(N, jnp.int32),
         "first_start": jnp.full(N, jnp.inf, jnp.float32),
+        "free_passes": jnp.int32(0),
         "key": key,
     }
     obs_dim = (layout.state_dim + 2 * R + W) if layout.requires_obs else W
@@ -764,6 +768,7 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
            "now": st["now"], "decisions": st["decisions"],
            "truncated": st["truncated"],
            "first_start": st["first_start"], "done": st["done"],
+           "free_passes": st["free_passes"],
            "actions": actions, "decided": decided}
     if collect:
         out["obs"] = obs_log
@@ -1014,7 +1019,8 @@ class DeviceSimulator:
                 rounds=int(decided.any(axis=1).sum()),
                 decisions=int(decided.sum()),
                 policy_calls=int(decided.any(axis=1).sum()),
-                max_batch=int(decided.sum(axis=1).max(initial=0)))
+                max_batch=int(decided.sum(axis=1).max(initial=0)),
+                free_passes=int(out["free_passes"]))
             return DeviceRollout(
                 actions=out["actions"], decided=decided,
                 stats=self.stats, obs=out.get("obs"), trace=tr,

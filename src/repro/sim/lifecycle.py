@@ -414,12 +414,30 @@ def device_attempt(fail_times, requeues, runtime):
 
 
 def device_free_units(mask_j, release, owner):
-    """Free every unit owned by a job in ``mask_j`` (N, J)."""
+    """Free every unit owned by a job in ``mask_j`` (N, J).
+
+    Returns ``(release, owner, passes)``.  Each pass clears, in every
+    environment at once, the units of its lowest-index job still set,
+    by comparing ``owner`` with that job; ``passes`` (int32) is the most
+    jobs any one environment frees, so a call with nothing to free costs
+    one ``any`` and no pass over the (N, U) units.  Free (-1) and
+    phantom (``PHANTOM_OWNER``) units never equal a job index."""
+    import jax
     import jax.numpy as jnp
 
-    hit = jnp.take_along_axis(mask_j, jnp.maximum(owner, 0), axis=1) \
-        & (owner >= 0)
-    return jnp.where(hit, 0.0, release), jnp.where(hit, -1, owner)
+    jidx = jnp.arange(mask_j.shape[1], dtype=owner.dtype)
+
+    def body(c):
+        rem, release, owner, passes = c
+        j = jnp.argmax(rem, axis=1).astype(owner.dtype)
+        hit = (owner == j[:, None]) & rem.any(axis=1)[:, None]
+        return (rem & (jidx[None, :] != j[:, None]),
+                jnp.where(hit, 0.0, release), jnp.where(hit, -1, owner),
+                passes + 1)
+
+    _, release, owner, passes = jax.lax.while_loop(
+        lambda c: c[0].any(), body, (mask_j, release, owner, jnp.int32(0)))
+    return release, owner, passes
 
 
 def device_kill(killed, now, demands, node_idx, max_requeues, st):
@@ -436,8 +454,9 @@ def device_kill(killed, now, demands, node_idx, max_requeues, st):
     work = demands * run_t[..., None]                      # (N, J, R)
     st["failed_area"] = st["failed_area"] + work.sum(axis=1)
     st["failed_work"] = st["failed_work"] + work[..., node_idx]
-    st["release"], st["owner"] = device_free_units(
+    st["release"], st["owner"], passes = device_free_units(
         killed, st["release"], st["owner"])
+    st["free_passes"] = st["free_passes"] + passes
     st["requeues"] = st["requeues"] + killed
     st["failed"] = st["failed"] | (killed & (st["requeues"] > max_requeues))
     st["started"] = st["started"] & ~killed
@@ -457,8 +476,9 @@ def device_apply_ends(t, act, demands, node_idx, max_requeues, st,
     due = running & (st["end"] == t[:, None]) & act[:, None]
     fin = due & ~st["cur_fail"] if has_kills else due
     st["finished"] = st["finished"] | fin
-    st["release"], st["owner"] = device_free_units(
+    st["release"], st["owner"], passes = device_free_units(
         fin, st["release"], st["owner"])
+    st["free_passes"] = st["free_passes"] + passes
     if has_kills:
         st = device_kill(due & st["cur_fail"], t, demands, node_idx,
                          max_requeues, st)

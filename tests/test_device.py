@@ -8,8 +8,8 @@ from repro.core import (AgentConfig, FCFSPolicy, GAConfig, GAOptimizer,
                         MRSchAgent, ScalarRLConfig, ScalarRLPolicy,
                         supports_batch, supports_device)
 from repro.kernels.window_pack.ops import pack_window
-from repro.sim import (DeviceSimulator, Job, ResourceSpec, SimConfig,
-                       Simulator, run_traces_device, sim_config)
+from repro.sim import (FINISHED, DeviceSimulator, Job, ResourceSpec,
+                       SimConfig, Simulator, run_traces_device, sim_config)
 from repro.workloads import ThetaConfig
 from repro.workloads.registry import build_jobs
 
@@ -123,6 +123,10 @@ def test_device_multi_env_matches_per_env_sequential():
     assert st.decisions == sum(r.decisions for r in ro.results)
     assert st.policy_calls == st.rounds
     assert 1 < st.max_batch <= 4
+    # Each pass frees at least one job in some environment.
+    finished = sum(j.state == FINISHED for r in ro.results for j in r.jobs)
+    assert 0 < st.free_passes <= finished
+    assert st.as_dict()["free_passes"] == st.free_passes
 
 
 def test_device_no_backfill_matches_sequential():
@@ -229,8 +233,8 @@ SCAN_PHASES = {"advance", "pack", "obs", "score", "start", "backfill_fit",
 def test_scan_phases_name_the_compiled_rollout(state_module):
     """Every phase of a round carries its ``mrsch.scan.*`` scope in the
     compiled program's op_names, flat (no op under two phases), with the
-    kernel scopes innermost; the one gather outside the live-round cond
-    (the event pump's ``device_free_units``) lies in ``advance``."""
+    kernel scopes innermost; no gather runs outside the live-round cond,
+    and the event pump's ``device_free_units`` loop lies in ``advance``."""
     import re
 
     import jax
@@ -259,8 +263,9 @@ def test_scan_phases_name_the_compiled_rollout(state_module):
     assert placed == ({("window_pack", "pack"), ("fused_mlp", "score")}
                       | ({("mha_fwd", "score")}
                          if state_module == "attention" else set()))
-    pump = [n for n in names
-            if n.endswith("take_along_axis)/gather") and "/cond/" not in n]
+    outside = [n for n in names if "/cond/" not in n]
+    assert not [n for n in outside if n.endswith("/gather")]
+    pump = [n for n in outside if n.endswith("/while") and scan.search(n)]
     assert pump and all("/mrsch.scan.advance/" in n for n in pump)
 
 

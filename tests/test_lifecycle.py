@@ -12,6 +12,7 @@ from repro.sim import (FAILED, FINISHED, DeviceSimulator, DrainEvent,
                        FaultSchedule, Job, ResourceSpec, SimConfig,
                        Simulator, VectorSimulator, pipeline_makespan,
                        workflow_components)
+from repro.sim.lifecycle import PHANTOM_OWNER, device_free_units
 from repro.workloads import ThetaConfig, build_jobs, get_scenario
 
 RES = [ResourceSpec("node", 4)]
@@ -161,6 +162,55 @@ def test_pipeline_makespan_averages_completed_components_only():
                           - min(j.submit for j in comp))
     assert r.metrics.pipeline_makespan == pytest.approx(np.mean(comp_spans))
     assert pipeline_makespan(r.jobs) == r.metrics.pipeline_makespan
+
+
+# --------------------------------------------------- device unit release
+def free_units_case(case: str, seed: int):
+    """(mask (N, J), release (N, U), owner (N, U)): owners mix free (-1),
+    phantom and job units at random, releases are nonzero where owned."""
+    rng = np.random.default_rng(seed)
+    n, j, u = 3, 8, 40
+    owner = rng.choice(np.r_[-1, PHANTOM_OWNER, np.arange(j)],
+                       size=(n, u)).astype(np.int32)
+    release = np.where(owner == -1, 0.0,
+                       rng.uniform(1.0, 1e4, (n, u))).astype(np.float32)
+    mask = rng.uniform(size=(n, j)) < 0.4
+    if case == "all_false":
+        mask[:] = False
+    elif case == "uneven":            # several jobs in env 0, none in env 1
+        mask[:] = False
+        mask[0, rng.choice(j, size=5, replace=False)] = True
+        mask[2, int(rng.integers(j))] = True
+    elif case == "non_contiguous":    # job 4 holds units apart in env 1
+        owner[1][owner[1] == 4] = 5
+        owner[1, [2, 9, 10, 31]] = 4
+        release[1, [2, 9, 10, 31]] = 500.0
+        mask[1] = False
+        mask[1, 4] = True
+    return mask, release, owner
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", ["random", "all_false", "uneven",
+                                  "non_contiguous"])
+def test_device_free_units_matches_owner_gather(case, seed):
+    """The pass-per-job release frees exactly what gathering the mask by
+    every unit's owner frees, in as many passes as the most jobs one
+    environment frees."""
+    import jax
+
+    mask, release, owner = free_units_case(case, seed)
+    hit = np.take_along_axis(mask, np.maximum(owner, 0), axis=1) \
+        & (owner >= 0)
+    got_release, got_owner, passes = jax.jit(device_free_units)(
+        mask, release, owner)
+    np.testing.assert_array_equal(np.asarray(got_release),
+                                  np.where(hit, 0.0, release))
+    np.testing.assert_array_equal(np.asarray(got_owner),
+                                  np.where(hit, -1, owner))
+    assert int(passes) == mask.sum(axis=1).max()
+    if case == "non_contiguous":
+        assert (np.asarray(got_owner)[1, [2, 9, 10, 31]] == -1).all()
 
 
 # ------------------------------------------------- three-engine parity pins
