@@ -3,8 +3,12 @@
 A site ranking candidate policies runs each over the same grid.  Set-up
 makes the traffic's ``envs`` traces of the configuration's ``trace_jobs``
 jobs and the weights of ``policies`` candidate policies from the seed,
-and one ``DeviceSimulator``; one whole greedy rollout and its per-env
-results warm every program and host path.  The window then runs whole
+and one ``DeviceSimulator``.  Where the traffic names a ``work_seed``,
+the traces and the policies are drawn from it, the same for every run,
+and the run's seed deals the traces to the environments in another order
+(and draws the environments the reference compares): every seed then
+does the same work.  One whole greedy rollout and its per-env results
+warm every program and host path.  The window then runs whole
 rollouts, policy after policy (the parameters are an argument of the
 compiled scan, so nothing recompiles), each ending with its per-env
 ``ScheduleMetrics``, until ``--seconds`` have passed and every policy
@@ -37,13 +41,25 @@ def _completed(results) -> int:
     return sum(sum(1 for j in r.jobs if j.end >= 0.0) for r in results)
 
 
+def inputs(cfg: dict, tf: dict, seed: int):
+    """The traces, in environment order, and the candidate policies'
+    weights: from the seed, or from the traffic's ``work_seed`` with the
+    traces dealt in an order drawn from the seed."""
+    work = int(tf.get("work_seed", seed))
+    traces = workload.make_traces(cfg, tf["trace"], work, int(tf["envs"]),
+                                  int(cfg["trace_jobs"]))
+    if "work_seed" in tf:
+        order = workload.rng_for(seed, 98).permutation(len(traces))
+        traces = [traces[i] for i in order]
+    params = [refnet.make_params(cfg, work, p) for p in range(int(tf["policies"]))]
+    return traces, params
+
+
 def build(cfg: dict, tf: dict, seed: int):
-    """Traces and candidate policies from the seed, and the program's
+    """Traces and candidate policies (``inputs``), and the program's
     simulator (holding the first policy)."""
     from repro.sim import DeviceSimulator, SimConfig
-    traces = workload.make_traces(cfg, tf["trace"], seed, int(tf["envs"]),
-                                  int(cfg["trace_jobs"]))
-    params = [refnet.make_params(cfg, seed, p) for p in range(int(tf["policies"]))]
+    traces, params = inputs(cfg, tf, seed)
     agent = program.agent(cfg, params[0])
     sim = DeviceSimulator(program.resources(cfg),
                           [program.jobs(cfg, t) for t in traces], agent,

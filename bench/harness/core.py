@@ -14,6 +14,8 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, List, Optional
 
+from .workload import capacities
+
 
 class Refused(RuntimeError):
     """The run cannot be measured here (no chip, unknown device, ...)."""
@@ -52,9 +54,10 @@ class Cell:
 
 def find_cell(root: Path, workload: str) -> Cell:
     """Resolve a cell of ``<root>/BENCHMARK.json`` by name: its
-    configuration file, ``bench/traffic/<traffic>.json``, the driver
-    that file names, ``bench/drivers/<driver>.py``, and the limits of the
-    numbers its correctness check compares, ``bench/limits/<cell>.json``."""
+    configuration file (each resource known and given a capacity),
+    ``bench/traffic/<traffic>.json``, the driver that file names,
+    ``bench/drivers/<driver>.py``, and the limits of the numbers its
+    correctness check compares, ``bench/limits/<cell>.json``."""
     root = Path(root)
     bench = root / "bench"
     spec = load_json(root / "BENCHMARK.json")
@@ -64,6 +67,7 @@ def find_cell(root: Path, workload: str) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in spec["configs"]}
     config = load_json(root / configs[w["config"]]["file"])
+    capacities(config)               # refuses a resource it does not know
     traffic = load_json(bench / "traffic" / f"{w['traffic']}.json")
     driver = load_module(bench / "drivers" / f"{traffic['driver']}.py",
                          "driver_" + traffic["driver"])

@@ -10,13 +10,16 @@ from typing import Dict, List
 
 import numpy as np
 
+from .workload import capacities
+
 BACKEND = "pallas"
+UNITS = {"power": "kW"}
 
 
 def resources(cfg: dict):
     from repro.sim import ResourceSpec
-    caps = {"node": cfg["nodes"], "bb": cfg["bb_units"]}
-    return [ResourceSpec(r, int(caps[r])) for r in cfg["resources"]]
+    return [ResourceSpec(r, c, UNITS.get(r, ""))
+            for r, c in zip(cfg["resources"], capacities(cfg))]
 
 
 def agent(cfg: dict, params=None):

@@ -30,6 +30,8 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from .workload import capacities
+
 DAY = 86400.0
 TTF_HORIZON = 30.0 * DAY
 
@@ -38,7 +40,7 @@ class Layout:
     """Observation layout of one configuration."""
 
     def __init__(self, config: dict):
-        self.caps = [int(config["nodes"]), int(config["bb_units"])]
+        self.caps = capacities(config)
         self.window = int(config["window"])
         self.state_module = config["state_module"]
         self.queue_cap = int(config.get("queue_cap", 0))

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .workload import CAPACITY_KEYS, capacities
+
 SLOPE = 0.2
 LN_EPS = 1e-5
 
@@ -54,7 +56,7 @@ def state_dim(cfg: dict) -> int:
     R, jd = len(cfg["resources"]), len(cfg["resources"]) + 2
     if cfg["state_module"] == "attention":
         return cfg["queue_cap"] * jd + 1 + 2 * R
-    return cfg["window"] * jd + 2 * (cfg["nodes"] + cfg["bb_units"])
+    return cfg["window"] * jd + 2 * sum(capacities(cfg))
 
 
 def dense_layers(cfg: dict, batch_rows: int) -> list:
@@ -263,7 +265,7 @@ def _scores(params, cfg_key, rows, mode):
 _NET_KEYS = ("resources", "window", "offsets", "temporal_weights",
              "state_module", "state_hidden", "state_out", "module_hidden",
              "stream_hidden", "queue_cap", "attn_dim", "attn_heads",
-             "attn_layers", "attn_mlp_mult", "nodes", "bb_units")
+             "attn_layers", "attn_mlp_mult", *CAPACITY_KEYS.values())
 
 
 def _freeze(cfg: dict):

@@ -19,6 +19,10 @@ TINY_TRAFFIC = {
     "tiny-backlog": {"driver": "rollout", "why": "CPU test", "envs": 3, "policies": 2,
                      "trace": {"scenarios": [], "compression": 6.0},
                      "reference_envs": 2, "trace_rollouts": 1},
+    "tiny-power-grid": {"driver": "rollout", "why": "CPU test", "envs": 3,
+                        "policies": 2,
+                        "trace": {"scenarios": ["S6", "S9"], "compression": 1.0},
+                        "reference_envs": 3, "trace_rollouts": 1},
 }
 
 TINY_LIMITS = {"widest_score_gap": 1e-4}
@@ -26,6 +30,7 @@ TINY_LIMITS = {"widest_score_gap": 1e-4}
 TINY_CELLS = [
     ("rollout.tiny-mlp.grid", "tiny-mlp", "tiny-grid", "eval_jobs_per_s"),
     ("rollout.tiny-attn.backlog", "tiny-attn", "tiny-backlog", "eval_jobs_per_s"),
+    ("rollout.tiny-power.grid", "tiny-power", "tiny-power-grid", "eval_jobs_per_s"),
 ]
 
 
@@ -35,20 +40,24 @@ def tiny_root(tmp: Path) -> Path:
     shutil.copytree(BENCH, tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
+
+    def kind(cell, config_file):     # a metric's cells: same driver and module
+        cfg = json.loads((REPO / config_file).read_text())
+        return cell.split(".")[0], cfg["state_module"]
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    real = {w["name"]: kind(w["name"], files[w["config"]])
+            for w in spec["workloads"]}
+    tiny = {w: kind(w, f"bench/tests/data/{c}.json") for w, c, _, _ in TINY_CELLS}
     names = {c for _, c, _, _ in TINY_CELLS}
     spec["configs"] = [{"name": n, "source": "CPU test",
                         "file": f"bench/tests/data/{n}.json", "reduced": [],
                         "why": "CPU test"} for n in sorted(names)]
     spec["workloads"] = [{"name": w, "config": c, "traffic": t, "chips": 1,
                           "why": "CPU test"} for w, c, t, _ in TINY_CELLS]
-    cells = [w for w, _, _, _ in TINY_CELLS]
     for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = [w for w in cells
-                              if any(w.split(".")[0] == o.split(".")[0]
-                                     and (w.split(".")[1].split("-")[1]
-                                          == o.split(".")[1].split("-")[1])
-                                     for o in m["workloads"])]
+            kinds = {real[o] for o in m["workloads"]}
+            m["workloads"] = [w for w in tiny if tiny[w] in kinds]
     (tmp / "BENCHMARK.json").write_text(json.dumps(spec, indent=1))
     for name, t in TINY_TRAFFIC.items():
         (tmp / "bench" / "traffic" / f"{name}.json").write_text(json.dumps(t))

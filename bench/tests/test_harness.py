@@ -148,3 +148,43 @@ def test_new_config_traffic_and_metric_by_name(tmp_path):
     assert line["metrics"]["decisions_per_round.eval"]["value"] >= 1.0
     line = helpers.run_cell(root, "rollout.tiny-wide.few", trace=0)
     assert set(line["metrics"]) == {"setup_s", "eval_jobs_per_s"}
+
+
+def _fixed_work_root(tmp_path, envs):
+    """A checkout whose tiny grid draws its traces and policies from a
+    ``work_seed``, as the chip's grid traffic does."""
+    root = helpers.tiny_root(tmp_path)
+    tf = {**helpers.TINY_TRAFFIC["tiny-grid"], "envs": envs,
+          "reference_envs": min(envs, 3), "work_seed": 2147483999}
+    (root / "bench" / "traffic" / "tiny-grid.json").write_text(json.dumps(tf))
+    return root
+
+
+def test_work_seed_deals_the_same_traces_and_policies_to_every_seed(tmp_path):
+    import jax
+    import numpy as np
+    cell = core.find_cell(_fixed_work_root(tmp_path, 8), "rollout.tiny-mlp.grid")
+    runs = [cell.driver.inputs(cell.config, cell.traffic, s)
+            for s in (2147483647, 2147483648 + 12345)]
+
+    def key(tr):
+        return tuple(np.concatenate([tr["submit"], tr["runtime"],
+                                     tr["demands"].ravel()]).tolist())
+    (ta, pa), (tb, pb) = runs
+    assert sorted(map(key, ta)) == sorted(map(key, tb))
+    assert list(map(key, ta)) != list(map(key, tb))
+    for a, b in zip(pa, pb):
+        assert all(np.array_equal(x, y) for x, y in zip(
+            jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
+
+
+def test_work_seed_runs_do_the_same_work_on_every_seed(tmp_path, capsys):
+    root = _fixed_work_root(tmp_path, 3)
+    notes = []
+    for seed in (7, 2147483648 + 7):
+        line = helpers.run_cell(root, "rollout.tiny-mlp.grid", seed=seed)
+        assert line["correct"], line["checks"]
+        err = capsys.readouterr().err
+        notes.append([w for w in err.split() if w.startswith(
+            ("live_rounds=", "decisions=", "jobs_per_rollout="))])
+    assert notes[0] == notes[1] and len(notes[0]) == 3
