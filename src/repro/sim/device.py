@@ -147,12 +147,20 @@ class DeviceStats:
     policy_calls: int = 0            # one in-graph score per active round
     max_batch: int = 0
     free_passes: int = 0             # (N, U) compare passes freeing units
+    # Per resource name, the (environment, round) pairs in which the
+    # resource set the EASY reservation made: it lacked free units for the
+    # selected job and its fit time was the reservation's (on a tie, every
+    # tied resource counts).  A reservation made again in a later round
+    # counts again.
+    reserved_by: Dict[str, int] = field(default_factory=dict)
 
     def as_dict(self) -> dict:
         return {"rounds": self.rounds, "decisions": self.decisions,
                 "policy_calls": self.policy_calls,
                 "max_batch": self.max_batch,
-                "free_passes": self.free_passes}
+                "free_passes": self.free_passes,
+                **{f"reserved_by_{name}": n
+                   for name, n in self.reserved_by.items()}}
 
 
 @dataclass
@@ -266,8 +274,9 @@ def _earliest_fit(layout: DeviceLayout, release, free, demand, now):
     (mirrors ``Cluster.earliest_fit_time``): the need-th smallest release
     per resource (free units sort first as 0.0), max over resources.
     Permanently drained units carry ``release = +inf`` and therefore
-    never count toward a future fit, exactly like the host."""
-    t_res = now
+    never count toward a future fit, exactly like the host.  Returns
+    ``t_res`` (N,) and each resource's own fit time (N, R)."""
+    t_res, t_cols = now, []
     for r, (off, cap) in enumerate(layout.segments):
         seg_sorted = jnp.sort(release[:, off:off + cap], axis=1)
         need = demand[:, r]
@@ -276,7 +285,8 @@ def _earliest_fit(layout: DeviceLayout, release, free, demand, now):
         t_r = jnp.where(need <= free[:, r], now,
                         jnp.where(need <= float(cap), kth, INF))
         t_res = jnp.maximum(t_res, t_r)
-    return t_res
+        t_cols.append(t_r)
+    return t_res, jnp.stack(t_cols, axis=1)
 
 
 def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
@@ -291,8 +301,13 @@ def _easy_backfill(layout: DeviceLayout, arrays, st, free, need, waiting,
     N, J, R = layout.n_envs, layout.n_jobs, layout.n_resources
     now = st["now"]
     with named_scope("mrsch.scan.backfill_fit"):
-        t_res = _earliest_fit(layout, st["release"], free, d_star, now)
+        t_res, t_r = _earliest_fit(layout, st["release"], free, d_star, now)
         do_bf = need & jnp.isfinite(t_res)
+        # The resources that set each reservation: short of free units
+        # for the selected job, with the reservation's fit time.
+        setters = do_bf[:, None] & (d_star > free) & (t_r == t_res[:, None])
+        st = {**st, "reserved_by": st["reserved_by"]
+              + setters.sum(axis=0, dtype=jnp.int32)}
         # Shadow: free units at t_res (estimated releases) minus the
         # reservation's demand, per resource.
         shadow_cols = []
@@ -591,6 +606,7 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
         "truncated": jnp.zeros(N, jnp.int32),
         "first_start": jnp.full(N, jnp.inf, jnp.float32),
         "free_passes": jnp.int32(0),
+        "reserved_by": jnp.zeros(R, jnp.int32),
         "key": key,
     }
     obs_dim = (layout.state_dim + 2 * R + W) if layout.requires_obs else W
@@ -769,6 +785,7 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
            "truncated": st["truncated"],
            "first_start": st["first_start"], "done": st["done"],
            "free_passes": st["free_passes"],
+           "reserved_by": st["reserved_by"],
            "actions": actions, "decided": decided}
     if collect:
         out["obs"] = obs_log
@@ -1020,7 +1037,9 @@ class DeviceSimulator:
                 decisions=int(decided.sum()),
                 policy_calls=int(decided.any(axis=1).sum()),
                 max_batch=int(decided.sum(axis=1).max(initial=0)),
-                free_passes=int(out["free_passes"]))
+                free_passes=int(out["free_passes"]),
+                reserved_by={r.name: int(n) for r, n in
+                             zip(self.resources, out["reserved_by"])})
             return DeviceRollout(
                 actions=out["actions"], decided=decided,
                 stats=self.stats, obs=out.get("obs"), trace=tr,

@@ -11,7 +11,8 @@ pool restricted to a range, for a controlled contention sweep:
 
 S6–S10 add a power profile to S1–S5 jobs: per-node draw uniform in
 100–215 W (KNL 7230 TDP 215 W), system budget 500 kW (scaled
-proportionally for reduced clusters).
+proportionally for reduced clusters).  A job's draw is clamped to the
+budget, so every job can start.
 """
 from __future__ import annotations
 
@@ -75,33 +76,38 @@ def derive_scenario(base: List[Job], cfg: ThetaConfig, name: str,
 
 
 def with_power(jobs: List[Job], cfg: ThetaConfig, seed: int = 2,
-               idle_w: float = 60.0, lo_w: float = 100.0,
-               hi_w: float = 215.0) -> List[Job]:
-    """Attach a power demand (kW units) to every job: nodes x per-node watts."""
+               lo_w: float = 100.0, hi_w: float = 215.0) -> List[Job]:
+    """Attach a power demand (kW units) to every job: nodes x per-node
+    watts, at least 1 kW and at most the budget
+    (``cfg.default_power_budget_kw()``, rounded as ``ThetaConfig.resources``
+    rounds the capacity).  The clamp exists so that every job can start:
+    a whole-machine Theta job draws up to 945 kW against a 500 kW budget."""
+    cap = int(round(cfg.default_power_budget_kw()))
     rng = np.random.default_rng(seed)
     out = []
     for j in jobs:
         nj = j.copy()
         per_node = rng.uniform(lo_w, hi_w)
-        nj.demands["power"] = max(1, int(math.ceil(
-            nj.demands["node"] * per_node / 1000.0)))
+        nj.demands["power"] = min(cap, max(1, int(math.ceil(
+            nj.demands["node"] * per_node / 1000.0))))
         out.append(nj)
     return out
 
 
 def build_scenarios(cfg: ThetaConfig, names: Sequence[str] = ("S1", "S2", "S3", "S4", "S5"),
                     power: bool = False, seed: int = 1) -> Dict[str, List[Job]]:
+    """Each named scenario's trace.  S6-S10 mirror S1-S5 with power
+    profiles, as does every name when ``power`` is set (``with_power``)."""
     base = generate_trace(cfg)
     out = {}
     for name in names:
-        key = name
         src = name
+        with_pw = power
         if name.startswith("S") and int(name[1:]) > 5:
-            # S6-S10 mirror S1-S5 with power profiles.
             src = f"S{int(name[1:]) - 5}"
-            power = True
+            with_pw = True
         jobs = derive_scenario(base, cfg, src, seed=seed)
-        if power:
+        if with_pw:
             jobs = with_power(jobs, cfg, seed=seed + 7)
-        out[key] = jobs
+        out[name] = jobs
     return out

@@ -10,7 +10,7 @@ from repro.core import (AgentConfig, FCFSPolicy, GAConfig, GAOptimizer,
 from repro.kernels.window_pack.ops import pack_window
 from repro.sim import (FINISHED, DeviceSimulator, Job, ResourceSpec,
                        SimConfig, Simulator, run_traces_device, sim_config)
-from repro.workloads import ThetaConfig
+from repro.workloads import ThetaConfig, build_scenarios
 from repro.workloads.registry import build_jobs
 
 RES = [ResourceSpec("node", 16), ResourceSpec("bb", 8)]
@@ -102,6 +102,25 @@ def test_device_equals_sequential_agent_registry(scenario, backend):
     assert_results_close(seq, ro.results[0])
 
 
+@pytest.mark.parametrize("scenario,backend", [
+    ("S6", "xla"), ("S7", "xla"), ("S8", "xla"), ("S9", "xla"),
+    ("S10", "xla"), ("S9", "pallas")])
+def test_device_equals_sequential_power_capped(scenario, backend):
+    """Three resources (§V-E, S6-S10): the device rollout reproduces the
+    sequential engine under the cluster's default power budget, and the
+    clamped draw lets every job start."""
+    theta = ThetaConfig.mini(seed=0, duration_days=0.4, jobs_per_day=110)
+    res = theta.resources(power_budget_kw=theta.default_power_budget_kw())
+    jobs = build_scenarios(theta, names=(scenario,), power=True)[scenario]
+    agent = small_agent(res, backend=backend)
+    seq, actions = seq_run(res, jobs, agent)
+    ro = DeviceSimulator(res, [jobs], agent).rollout()
+    assert env_actions(ro, 0) == actions
+    assert_results_close(seq, ro.results[0])
+    assert seq.n_unstarted == 0
+    assert set(ro.stats.reserved_by) == {"node", "bb", "power"}
+
+
 def test_device_equals_sequential_scalar_rl():
     jobs = synth_jobs(7)
     rl = ScalarRLPolicy(RES, ScalarRLConfig(hidden=(16, 8)))
@@ -136,6 +155,38 @@ def test_device_no_backfill_matches_sequential():
                        SimConfig(backfill=False)).run()
     ro = DeviceSimulator(RES, [jobs], FCFSPolicy(), cfg).rollout()
     assert_results_close(seq_nb, ro.results[0])
+
+
+# ------------------------------------------------------ reservation counter
+RES3 = [ResourceSpec("node", 4), ResourceSpec("bb", 4),
+        ResourceSpec("power", 4, "kW")]
+
+
+@pytest.mark.parametrize("short", [("node",), ("bb",), ("power",),
+                                   ("node", "power")])
+def test_reserved_by_counts_the_resource_that_is_short(short):
+    """Job 0 leaves one unit of each ``short`` resource free and runs to
+    t=100; job 1 needs two of them, so it reserves at t=100; job 2 fits
+    beside the reservation (shadow) and is backfilled, and no event
+    falls before t=100.  The one reservation lands on the short
+    resources alone (a tie counts each)."""
+    def demands(n_short, n_other):
+        return {r.name: n_short if r.name in short else n_other
+                for r in RES3}
+    jobs = [Job(jid=0, submit=0.0, runtime=100.0, walltime=100.0,
+                demands=demands(3, 1)),
+            Job(jid=1, submit=0.0, runtime=50.0, walltime=50.0,
+                demands=demands(2, 1)),
+            Job(jid=2, submit=0.0, runtime=150.0, walltime=150.0,
+                demands=demands(1, 1))]
+    seq, actions = seq_run(RES3, jobs, FCFSPolicy())
+    ro = DeviceSimulator(RES3, [jobs], FCFSPolicy()).rollout()
+    assert env_actions(ro, 0) == actions
+    assert_results_close(seq, ro.results[0])
+    assert [j.start for j in ro.results[0].jobs] == [0.0, 100.0, 0.0]
+    stats = ro.stats.as_dict()
+    assert {r.name: stats[f"reserved_by_{r.name}"] for r in RES3} == {
+        r.name: int(r.name in short) for r in RES3}
 
 
 # ------------------------------------------------------------ rollout extras

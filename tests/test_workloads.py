@@ -1,4 +1,6 @@
 """Workload generator statistics vs the published trace properties."""
+import math
+
 import numpy as np
 import pytest
 
@@ -50,11 +52,30 @@ def test_scenarios_match_table_iii(name):
 
 def test_power_profiles():
     cfg = ThetaConfig.mini(seed=2, duration_days=5)
+    budget = int(round(cfg.default_power_budget_kw()))
     jobs = with_power(generate_trace(cfg), cfg)
     for j in jobs:
         watts = j.demands["power"] * 1000.0
         assert watts >= j.demands["node"] * 100.0 - 1000
         assert watts <= j.demands["node"] * 215.0 + 1000
+        assert j.demands["power"] <= budget
+
+
+@pytest.mark.parametrize("name", ["S6", "S7", "S8", "S9"])
+def test_full_theta_power_is_clamped_to_the_budget(name):
+    """At full Theta scale (500 kW) the largest jobs would draw up to
+    943 kW; clamped, they ask for the whole budget and no more."""
+    cfg = ThetaConfig()
+    jobs = build_scenarios(cfg, names=(name,))[name]
+    power = np.array([j.demands["power"] for j in jobs])
+    assert power.min() >= 1 and power.max() <= 500
+    # The unclamped draw, from with_power's own stream (seed 1 + 7).
+    rng = np.random.default_rng(8)
+    raw = np.array([max(1, math.ceil(j.demands["node"]
+                                     * rng.uniform(100.0, 215.0) / 1000.0))
+                    for j in jobs])
+    assert (raw > 500).sum() > 100
+    assert np.array_equal(power, np.minimum(raw, 500))
 
 
 def test_curriculum_structure():
