@@ -21,7 +21,7 @@ from ..nn.optim import AdamState, adam_init, adam_update
 from ..sim.cluster import ResourceSpec
 from ..sim.simulator import SchedContext
 from .dfp import (DFPConfig, action_values, greedy_actions_packed,
-                  init_params, loss_fn)
+                  init_params, loss_fn, prepare_params)
 from .encoding import (EncodingConfig, decision_row_dim, encode_decision_row,
                        encode_measurement, encode_state, pad_decision_rows)
 from .goal import ctx_goal
@@ -163,6 +163,13 @@ class MRSchAgent:
     def init_state(self):
         """Policy-state pytree for the device rollout (the parameters)."""
         return self.params
+
+    def prepare_state(self, params):
+        """The rollout's once-per-rollout form of ``init_state()``: on the
+        ``pallas`` backend every dense layer padded to the fused kernel's
+        blocks (``dfp.prepare_params``), so the scan's rounds reuse one
+        padded copy.  Pure; ``self.params`` keeps the logical layout."""
+        return prepare_params(params, self.dfp)
 
     def score_window(self, params, obs) -> jnp.ndarray:
         """Action values from packed decision rows (pure, traceable).

@@ -22,6 +22,13 @@ the policy must now be callable *inside* a traced program:
     ``jax.numpy`` ops so the same function serves the jitted device
     rollout and the host-side batched adapter below.
 
+``prepare_state(policy_state)``
+    Optional: the state in the form ``score_window`` runs fastest when
+    one rollout scores many rounds with it (the device engine calls it
+    once per rollout, inside its program, before the scan).  Pure; the
+    identity when absent.  ``MRSchAgent`` on the ``pallas`` backend pads
+    its dense layers here.
+
 ``select(ctx)``
     The host-side single-decision stage (unchanged API — external
     callers of ``agent.select`` keep working; ``SchedulingPolicy`` in

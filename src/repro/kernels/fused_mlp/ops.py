@@ -6,6 +6,10 @@ carries a ``jax.custom_vjp`` whose backward pass runs the fused
 dgrad/wgrad kernels — so both DFP inference *and* the ``lax.scan``
 training bursts stay inside the kernel layer.
 
+``pad_weights`` + ``fused_mlp_padded`` split the weight's pad from the
+forward, for loops whose weights do not change (the device rollout
+pads the policy's layers once per rollout, not once per round).
+
 Block sizes are autotuned per (M, K, N) problem shape (see
 ``autotune_blocks``), keyed on the *padded* batch the caller actually
 produces — the batched rollout engine pads its decision batch to a
@@ -64,18 +68,29 @@ def _fused_mlp_2d(x, w, b, activation, slope, block_m, block_n, block_k,
                        block_k, interpret)
 
 
+def _pad_weights(w, b, block_n, block_k):
+    return (_pad_to(_pad_to(w, block_k, 0), block_n, 1),
+            _pad_to(b, block_n, 0))
+
+
+def _forward_padded(x, wp, bp, n, activation, slope, block_m, block_n,
+                    block_k, interpret):
+    """act(x @ w + b)[:, :n] from a weight and bias already padded to
+    their (block_k, block_n) multiples; only ``x`` is padded here."""
+    M = x.shape[0]
+    xp = _pad_to(_pad_to(x, block_m, 0), block_k, 1)
+    y = fused_mlp_layer(xp, wp, bp, activation=activation, slope=slope,
+                        block_m=block_m, block_n=block_n, block_k=block_k,
+                        interpret=interpret)
+    return y[:M, :n]
+
+
 def _forward_2d(x, w, b, activation, slope, block_m, block_n, block_k,
                 interpret):
     with named_scope("mrsch.kernel.fused_mlp"):
-        M, K = x.shape
-        N = w.shape[1]
-        xp = _pad_to(_pad_to(x, block_m, 0), block_k, 1)
-        wp = _pad_to(_pad_to(w, block_k, 0), block_n, 1)
-        bp = _pad_to(b, block_n, 0)
-        y = fused_mlp_layer(xp, wp, bp, activation=activation, slope=slope,
-                            block_m=block_m, block_n=block_n,
-                            block_k=block_k, interpret=interpret)
-        return y[:M, :N]
+        wp, bp = _pad_weights(w, b, block_n, block_k)
+        return _forward_padded(x, wp, bp, w.shape[1], activation, slope,
+                               block_m, block_n, block_k, interpret)
 
 
 def _fused_mlp_fwd(x, w, b, activation, slope, block_m, block_n, block_k,
@@ -147,6 +162,44 @@ def fused_mlp(x, w, b, *, activation: str = "leaky_relu", slope: float = 0.2,
     y = _fused_mlp_jit(x, w, b, activation, float(slope), bm, bn, bk,
                        bool(interpret))
     return y[0] if squeeze else y
+
+
+def pad_weights(w, b):
+    """Pad a dense layer's (K, N) weight and (N,) bias to the blocks that
+    ``fused_mlp`` autotunes for it, for ``fused_mlp_padded``.
+
+    The N and K blocks depend on (K, N) alone, so one padded copy serves
+    every batch size: a caller whose weights are invariant over a loop
+    pads them once before it instead of once per call inside it.
+    """
+    K, N = w.shape
+    _, bn, bk = autotune_blocks(1, K, N)
+    with named_scope("mrsch.kernel.fused_mlp"):
+        return _pad_weights(w, b, bn, bk)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _fused_mlp_padded_jit(x, wp, bp, n, activation, slope, interpret):
+    M, K = x.shape
+    bm, bn, bk = autotune_blocks(M, K, n)
+    if wp.shape != (-(-K // bk) * bk, -(-n // bn) * bn):
+        raise ValueError(f"weight {wp.shape} is not ({K}, {n}) padded to "
+                         f"blocks ({bk}, {bn}); pad it with pad_weights")
+    with named_scope("mrsch.kernel.fused_mlp"):
+        return _forward_padded(x, wp, bp, n, activation, slope, bm, bn, bk,
+                               interpret)
+
+
+def fused_mlp_padded(x, wp, bp, n: int, *, activation: str = "leaky_relu",
+                     slope: float = 0.2, interpret: bool | None = None):
+    """``fused_mlp(x, w, b)`` for 2-D ``x`` from ``(wp, bp) =
+    pad_weights(w, b)`` and the layer's logical output width ``n``, bit
+    for bit: the same blocks, kernel and K order, and the zero padding
+    adds exactly 0.  Forward only (no VJP)."""
+    if interpret is None:
+        interpret = interpret_kernels()
+    return _fused_mlp_padded_jit(x, wp, bp, int(n), activation, float(slope),
+                                 bool(interpret))
 
 
 def dfp_state_module(x, layers, *, interpret: bool | None = None):

@@ -15,13 +15,21 @@ the parameter layout):
 
 Activations are named (strings), not callables, so the Pallas epilogue
 can fuse them; ``None`` means linear.
+
+``pad_dense_layers`` turns a param pytree's dense layers into
+``PaddedDense`` (the kernel's padded weight and bias), which the
+``pallas`` forward consumes as is: a caller that runs the same weights
+many times pads them once.  Checkpoints keep the logical layout.
 """
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Optional
 
+import jax
+
 from ..kernels.fused_mlp.kernel import _apply_activation, _check_activation
-from ..kernels.fused_mlp.ops import fused_mlp
+from ..kernels.fused_mlp.ops import fused_mlp, fused_mlp_padded, pad_weights
 from .modules import Params, dense_apply
 
 BACKENDS = ("xla", "pallas")
@@ -43,11 +51,59 @@ def _named_activation(y, activation: Optional[str], slope: float):
     return _apply_activation(y, activation, slope)
 
 
+@jax.tree_util.register_pytree_node_class
+@dataclass(frozen=True)
+class PaddedDense:
+    """A dense layer for the ``pallas`` backend, its weight and bias
+    padded by ``fused_mlp.pad_weights``; ``n`` is the logical output
+    width (static)."""
+    w: jax.Array
+    b: jax.Array
+    n: int
+
+    def tree_flatten(self):
+        return (self.w, self.b), self.n
+
+    @classmethod
+    def tree_unflatten(cls, n, children):
+        return cls(*children, n)
+
+
+def _is_dense(node) -> bool:
+    return (isinstance(node, dict) and set(node) == {"w", "b"}
+            and node["w"].ndim == 2)
+
+
+def pad_dense_layers(params):
+    """``params`` with every ``{'w', 'b'}`` layer of a 2-D weight as a
+    ``PaddedDense``; every other leaf as it is.  For a pytree that the
+    ``pallas`` ``dense_forward`` runs; the input is not modified."""
+    def pad(node):
+        if not _is_dense(node):
+            return node
+        return PaddedDense(*pad_weights(node["w"], node["b"]),
+                           node["w"].shape[1])
+    return jax.tree_util.tree_map(pad, params, is_leaf=_is_dense)
+
+
+def count_padded(params) -> int:
+    """The number of ``PaddedDense`` layers in a pytree."""
+    def is_padded(node):
+        return isinstance(node, PaddedDense)
+    return sum(map(is_padded,
+                   jax.tree_util.tree_leaves(params, is_leaf=is_padded)))
+
+
 def dense_forward(layer: Params, x, activation: Optional[str] = None, *,
                   slope: float = 0.2, backend: str = "xla",
                   interpret: Optional[bool] = None):
-    """One dense layer + optional named activation on the given backend."""
+    """One dense layer + optional named activation on the given backend
+    (a ``PaddedDense`` layer on ``pallas`` only)."""
     if resolve_backend(backend) == "pallas":
+        if isinstance(layer, PaddedDense):
+            return fused_mlp_padded(x, layer.w, layer.b, layer.n,
+                                    activation=activation or "linear",
+                                    slope=slope, interpret=interpret)
         return fused_mlp(x, layer["w"], layer["b"],
                          activation=activation or "linear", slope=slope,
                          interpret=interpret)

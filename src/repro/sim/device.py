@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.window_pack.ops import pack_window
+from ..nn.backend import count_padded
 from ..obs.profiling import annotate, named_scope
 from ..obs.trace import Tracer
 from .cluster import TTF_HORIZON, Cluster, ResourceSpec
@@ -147,6 +148,7 @@ class DeviceStats:
     policy_calls: int = 0            # one in-graph score per active round
     max_batch: int = 0
     free_passes: int = 0             # (N, U) compare passes freeing units
+    weights_prepared: int = 0        # dense layers entering the scan padded
     # Per resource name, the (environment, round) pairs in which the
     # resource set the EASY reservation made: it lacked free units for the
     # selected job and its fit time was the reservation's (on a tie, every
@@ -159,6 +161,7 @@ class DeviceStats:
                 "policy_calls": self.policy_calls,
                 "max_batch": self.max_batch,
                 "free_passes": self.free_passes,
+                "weights_prepared": self.weights_prepared,
                 **{f"reserved_by_{name}": n
                    for name, n in self.reserved_by.items()}}
 
@@ -554,10 +557,14 @@ def _build_obs_attention(layout: DeviceLayout, arrays, st, waiting,
          q_valid[:, :W].astype(jnp.float32)], axis=1)
 
 
-def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
-                    collect: bool, trace: bool, arrays,
+def _device_rollout(layout: DeviceLayout, score_fn, prepare_fn,
+                    explore: bool, collect: bool, trace: bool, arrays,
                     faults: DeviceFaults, policy_state, eps, key):
     """The whole N-env x T-round rollout as one traced program.
+
+    ``prepare_fn`` (the policy's ``prepare_state``) runs once, before the
+    scan, so what it makes of ``policy_state`` (the pallas agent's padded
+    weights) enters the rounds as a loop invariant.
 
     ``trace`` (static) additionally scans out per-round lifecycle deltas
     and decision extras — tiny boolean/int arrays carried through the
@@ -611,7 +618,9 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
     }
     obs_dim = (layout.state_dim + 2 * R + W) if layout.requires_obs else W
 
-    # Constant per rollout: keep the concat out of the per-round body.
+    # Constant per rollout: keep the concat and the policy's weight
+    # preparation out of the per-round body.
+    policy_state = prepare_fn(policy_state)
     feats = jnp.concatenate(
         [arrays["static_feats"], arrays["submit_feat"][..., None]],
         axis=-1)
@@ -797,6 +806,10 @@ def _device_rollout(layout: DeviceLayout, score_fn, explore: bool,
 
 
 # ====================================================================== host
+def _identity(policy_state):
+    return policy_state
+
+
 class DeviceSimulator:
     """N jobsets, one shared cluster spec, one jitted rollout program.
 
@@ -881,6 +894,8 @@ class DeviceSimulator:
         self.arrays = self._pack(self.jobsets)
         self.faults_arrays = self._pack_faults(self._faults)
         self.stats = DeviceStats()
+        self._prepare = getattr(policy, "prepare_state", None) or _identity
+        self._weights_prepared: Optional[int] = None
         self._jitted: Dict[Tuple[bool, bool, bool], object] = {}
 
     def _fault_rounds(self) -> int:
@@ -994,8 +1009,16 @@ class DeviceSimulator:
         if key not in self._jitted:
             self._jitted[key] = jax.jit(functools.partial(
                 _device_rollout, self.layout, self.policy.score_window,
-                explore, collect, trace))
+                self._prepare, explore, collect, trace))
         return self._jitted[key]
+
+    def _count_prepared(self, policy_state) -> int:
+        """Dense layers that enter the scan pre-padded, from the shapes
+        of the policy's prepared state (no device work)."""
+        if self._weights_prepared is None:
+            self._weights_prepared = count_padded(
+                jax.eval_shape(self._prepare, policy_state))
+        return self._weights_prepared
 
     def rollout(self, eps: Optional[float] = None, seed: int = 0,
                 collect: bool = False, trace: bool = False) -> DeviceRollout:
@@ -1016,10 +1039,11 @@ class DeviceSimulator:
         its outputs to the host.
         """
         explore = eps is not None
+        policy_state = self.policy.init_state()
         with annotate("mrsch.device.rollout"):
             with annotate("mrsch.device.dispatch"):
                 raw = self._fn(explore, collect, trace)(
-                    self.arrays, self.faults_arrays, self.policy.init_state(),
+                    self.arrays, self.faults_arrays, policy_state,
                     jnp.float32(0.0 if eps is None else eps),
                     jax.random.PRNGKey(seed))
             with annotate("mrsch.device.fetch"):
@@ -1038,6 +1062,7 @@ class DeviceSimulator:
                 policy_calls=int(decided.any(axis=1).sum()),
                 max_batch=int(decided.sum(axis=1).max(initial=0)),
                 free_passes=int(out["free_passes"]),
+                weights_prepared=self._count_prepared(policy_state),
                 reserved_by={r.name: int(n) for r, n in
                              zip(self.resources, out["reserved_by"])})
             return DeviceRollout(

@@ -318,6 +318,49 @@ def test_scan_phases_name_the_compiled_rollout(state_module):
     assert not [n for n in outside if n.endswith("/gather")]
     pump = [n for n in outside if n.endswith("/while") and scan.search(n)]
     assert pump and all("/mrsch.scan.advance/" in n for n in pump)
+    # The dense layers' weights and biases are padded once, before the
+    # scan, under the kernel's scope and no phase; a round pads only x.
+    from repro.nn.backend import PaddedDense
+
+    def padded(node):
+        return isinstance(node, PaddedDense)
+    prepared = jax.eval_shape(agent.prepare_state, agent.init_state())
+    layers = list(filter(padded, jax.tree_util.tree_leaves(
+        prepared, is_leaf=padded)))
+    shapes = ({l.w.shape for l in layers} | {l.b.shape for l in layers}
+              | {(l.w.shape[0], l.n) for l in layers})
+    pads = [(tuple(int(d) for d in dims.split(",") if d), name)
+            for dims, name in re.findall(
+                r'\[([\d,]*)\]\S* pad\(.*op_name="([^"]*)"', text)]
+    weight_pads = [name for shape, name in pads if shape in shapes]
+    assert weight_pads
+    assert all("mrsch.kernel.fused_mlp/" in n and not scan.search(n)
+               for n in weight_pads)
+    ro = sim.rollout()
+    assert ro.stats.weights_prepared == len(layers) == {
+        "mlp": 3 + 3 + 3 + 2 + 2, "attention": 2 + 6 + 1 + 3 + 3 + 2 + 2,
+    }[state_module]
+    assert ro.stats.as_dict()["weights_prepared"] == len(layers)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("scenario", ["S2", "diurnal-heavy"])
+def test_prepared_weights_take_the_same_actions(scenario, backend):
+    """The rollout with the agent's once-per-rollout weight preparation
+    takes the actions of one that pads the weights in every round (the
+    identity hook), on the registry parity fixtures; on ``xla`` nothing
+    is prepared."""
+    theta = ThetaConfig.mini(seed=0, duration_days=0.4, jobs_per_day=110)
+    res = theta.resources()
+    jobsets = [build_jobs(scenario, theta, seed=s) for s in (1, 2)]
+    agent = small_agent(res, backend=backend)
+    ro = DeviceSimulator(res, jobsets, agent).rollout()
+    agent.prepare_state = lambda params: params
+    per_round = DeviceSimulator(res, jobsets, agent).rollout()
+    assert np.array_equal(ro.actions, per_round.actions)
+    assert np.array_equal(ro.decided, per_round.decided)
+    assert ro.stats.weights_prepared == (13 if backend == "pallas" else 0)
+    assert per_round.stats.weights_prepared == 0
 
 
 def test_rollout_and_results_open_device_spans(monkeypatch):

@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels.flash_attention.ops import flash_attention, mha
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.fused_mlp.ops import fused_mlp
+from repro.kernels.fused_mlp.ops import (fused_mlp, fused_mlp_padded,
+                                        pad_weights)
 from repro.kernels.fused_mlp.ref import fused_mlp_layer_ref
 from repro.kernels.ssd.ops import ssd
 from repro.kernels.ssd.ref import ssd_ref
@@ -46,6 +47,65 @@ def test_fused_mlp_dfp_sizes():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=5e-2,
                                atol=5e-2)
+
+
+# ------------------------------------------- fused_mlp, pre-padded weights
+@pytest.mark.parametrize("m,k,n", [(5, 300, 200), (5, 512, 200),
+                                   (37, 1000, 513), (1, 64, 32)])
+@pytest.mark.parametrize("act", ["leaky_relu", "relu", "tanh", "linear"])
+def test_fused_mlp_padded_is_bit_identical(m, k, n, act):
+    """Padding the weight once ahead of the call changes no bit: the same
+    blocks and K order, and the zero rows and columns add exactly 0."""
+    key = jax.random.PRNGKey(m * 13 + k)
+    x = jax.random.normal(key, (m, k))
+    w = jax.random.normal(jax.random.fold_in(key, 1), (k, n)) * 0.05
+    b = jax.random.normal(jax.random.fold_in(key, 2), (n,))
+    wp, bp = pad_weights(w, b)
+    assert wp.shape[0] % 128 == 0 and wp.shape[1] % 128 == 0
+    assert bp.shape == (wp.shape[1],)
+    got = fused_mlp_padded(x, wp, bp, n, activation=act)
+    assert got.shape == (m, n)
+    assert jnp.array_equal(got, fused_mlp(x, w, b, activation=act))
+
+
+def test_fused_mlp_padded_refuses_an_unpadded_weight():
+    x = jnp.ones((5, 300))
+    w, b = jnp.ones((300, 200)), jnp.zeros(200)
+    with pytest.raises(ValueError, match="pad_weights"):
+        fused_mlp_padded(x, w, b, 200)
+
+
+@pytest.mark.parametrize("backend,state_module,padded", [
+    ("pallas", "mlp", 13), ("pallas", "attention", 19),
+    ("pallas", "cnn", 10), ("xla", "mlp", 0)])
+def test_prepare_state_keeps_the_logical_params(backend, state_module,
+                                                padded):
+    """The agent's prepare hook pads every dense layer the pallas forward
+    runs (a copy), scores bit for bit as the logical params do, and
+    leaves ``agent.params`` as it was."""
+    from repro.core import AgentConfig, MRSchAgent
+    from repro.nn.backend import count_padded
+    from repro.sim import ResourceSpec
+    agent = MRSchAgent(
+        [ResourceSpec("node", 16), ResourceSpec("bb", 8)],
+        AgentConfig(state_hidden=(32, 16), state_out=8, module_hidden=4,
+                    backend=backend, state_module=state_module,
+                    queue_cap=16, attn_dim=8, attn_heads=2, attn_layers=1))
+    params = agent.init_state()
+    before = jax.tree_util.tree_map(np.asarray, params)
+    prepared = agent.prepare_state(params)
+    assert count_padded(prepared) == padded
+    assert (prepared is params) == (padded == 0)
+    assert agent.params is params
+    assert (jax.tree_util.tree_structure(params)
+            == jax.tree_util.tree_structure(before))
+    for old, new in zip(jax.tree_util.tree_leaves(before),
+                        jax.tree_util.tree_leaves(params)):
+        assert old.shape == new.shape and np.array_equal(old, new)
+    obs = jax.random.uniform(jax.random.PRNGKey(3),
+                             (6, agent.enc.state_dim + 4 + 10))
+    assert jnp.array_equal(agent.score_window(prepared, obs),
+                           agent.score_window(params, obs))
 
 
 # ------------------------------------------------- fused_mlp custom VJP
